@@ -70,4 +70,6 @@ def main(n: int = 0, seed: int = 0, verbose: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main(n=int(sys.argv[1]) if len(sys.argv) > 1 else 0, verbose=True)

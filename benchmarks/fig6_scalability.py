@@ -113,5 +113,7 @@ def main(seed=0, verbose=False, sizes=None):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     cli_sizes = tuple(int(a) for a in sys.argv[1:]) or None
     main(verbose=True, sizes=cli_sizes)

@@ -201,4 +201,6 @@ def main(argv=None) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

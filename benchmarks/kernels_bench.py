@@ -1,8 +1,11 @@
-"""Kernel microbenchmarks: Pallas (interpret on CPU — structural check) vs
-the pure-jnp reference, plus the XLA fallback attention in the model.
+"""Kernel microbenchmarks: Pallas vs the pure-jnp reference, plus the XLA
+fallback attention in the model.
 
-On CPU the interpret-mode numbers are NOT performance claims; the derived
-column records bytes/flops so the TPU roofline expectation is visible."""
+The kernels compile on TPU and run in interpret mode elsewhere; each row's
+name carries which (``_pallas`` or ``_interp``) and its derived column the
+device.  On CPU the interpret-mode numbers are NOT performance claims; the
+derived column records bytes/flops so the TPU roofline expectation is
+visible."""
 from __future__ import annotations
 
 import time
@@ -26,6 +29,8 @@ def _time(fn, *args, iters=3):
 
 
 def main():
+    mode = "pallas" if jax.default_backend() == "tpu" else "interp"
+    dev = f"device={jax.devices()[0].device_kind}"
     key = jax.random.PRNGKey(0)
     B, S, H, D = (1, 256, 4, 64) if FAST else (4, 1024, 8, 128)
     q = jax.random.normal(key, (B, S, H, D), jnp.float32)
@@ -36,11 +41,9 @@ def main():
         c.transpose(0, 2, 1, 3).reshape(B * H, S, D)), q, k, v)
     flops = 4 * B * H * S * S * D
     emit("kernels/attention_ref_xla", us_ref, f"flops={flops:.3g}")
-    us_pal = _time(lambda a, b, c: flash_attention(a, b, c, interpret=True,
-                                                   block_q=128, block_k=128),
-                   q, k, v)
-    emit("kernels/flash_attention_interp", us_pal,
-         f"flops={flops:.3g};note=interpret-mode-structural")
+    us_pal = _time(lambda a, b, c: flash_attention(a, b, c, block_q=128,
+                                                   block_k=128), q, k, v)
+    emit(f"kernels/flash_attention_{mode}", us_pal, f"flops={flops:.3g};{dev}")
 
     N, L, Dd = (8, 8, 4096) if FAST else (32, 60, 65536)
     U = jax.random.normal(key, (N, L, Dd))
@@ -48,16 +51,18 @@ def main():
     w = jnp.ones((N,))
     us = _time(lambda a, b, c: layer_agg_ref(a, b, c), U, M, w)
     emit("kernels/layer_agg_ref_xla", us, f"bytes={U.size * 4:.3g}")
-    us = _time(lambda a, b, c: layer_agg_op(a, b, c, interpret=True), U, M, w)
-    emit("kernels/layer_agg_interp", us, f"bytes={U.size * 4:.3g}")
+    us = _time(lambda a, b, c: layer_agg_op(a, b, c), U, M, w)
+    emit(f"kernels/layer_agg_{mode}", us, f"bytes={U.size * 4:.3g};{dev}")
 
     x = jax.random.normal(key, (512, 1024), jnp.float32)
     s = jnp.ones((1024,))
     us = _time(lambda a, b: rmsnorm_ref(a, b), x, s)
     emit("kernels/rmsnorm_ref_xla", us, f"bytes={x.size * 4:.3g}")
-    us = _time(lambda a, b: rmsnorm_op(a, b, interpret=True), x, s)
-    emit("kernels/rmsnorm_interp", us, f"bytes={x.size * 4:.3g}")
+    us = _time(lambda a, b: rmsnorm_op(a, b), x, s)
+    emit(f"kernels/rmsnorm_{mode}", us, f"bytes={x.size * 4:.3g};{dev}")
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

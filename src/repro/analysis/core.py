@@ -183,8 +183,7 @@ class RepoIndex:
     """Parsed view of every python file under the lint roots."""
 
     def __init__(self, repo_root: str, src_rel: str = "src",
-                 package: str = "repro",
-                 exclude: Sequence[str] = ("_vendor",)):
+                 package: str = "repro"):
         self.repo_root = os.path.abspath(repo_root)
         self.package = package
         self.modules: Dict[str, Module] = {}
@@ -192,8 +191,8 @@ class RepoIndex:
         self.parse_errors: List[Finding] = []
         src_dir = os.path.join(self.repo_root, src_rel, package)
         for dirpath, dirnames, filenames in os.walk(src_dir):
-            dirnames[:] = sorted(d for d in dirnames if d not in exclude
-                                 and not d.startswith("."))
+            dirnames[:] = sorted(d for d in dirnames
+                                 if not d.startswith("."))
             for name in sorted(filenames):
                 if name.endswith(".py"):
                     self._add(os.path.join(dirpath, name), src_dir)

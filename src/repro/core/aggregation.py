@@ -162,6 +162,10 @@ class StackTemplate(NamedTuple):
     group_rows: Tuple[Tuple[int, int], ...]  # (row_start, row_stop) per group
 
 
+#: row-count multiple of every stack template (the f32 sublane tile)
+ROW_ALIGN = 8
+
+
 def build_stack_template(group_trees: Sequence, seg: int = 1024
                          ) -> StackTemplate:
     sizes, rows, r = [], [], 0
@@ -171,6 +175,9 @@ def build_stack_template(group_trees: Sequence, seg: int = 1024
         sizes.append(n)
         rows.append((r, r + nr))
         r += nr
+    # trailing zero rows (mask 0) align R to the kernel's 8-row f32 tile,
+    # so layer_agg never has to copy the [N, R, seg] stack to pad it
+    r = -(-r // ROW_ALIGN) * ROW_ALIGN
     return StackTemplate(seg=int(seg), n_rows=r, group_sizes=tuple(sizes),
                          group_rows=tuple(rows))
 
@@ -215,6 +222,10 @@ def stack_group_rows(group_trees: Sequence, template: StackTemplate,
         else:
             flat = jnp.pad(flat, (0, pad))
             parts.append(("rows", flat.reshape(nr, template.seg)))
+    tail = template.n_rows - (template.group_rows[-1][1]
+                              if template.group_rows else 0)
+    if tail:
+        parts.append(("zeros", tail))
     out = []
     for kind, v in parts:
         if kind == "rows":

@@ -204,8 +204,6 @@ def aggregate_drfl_stacked(global_params, buckets, server_lr: float = 1.0,
     a non-finite pad row can no longer poison the numerator).
     ``with_stats`` returns ``(params, valid)`` with the [N_total] row
     validity left ON DEVICE — callers batch the pull."""
-    if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu"
     fam = resolve_family(family)
     model_idxs, deltas, ws, alphas = [], [], [], []
     any_stale = False
@@ -226,7 +224,7 @@ def aggregate_drfl_stacked(global_params, buckets, server_lr: float = 1.0,
         global_params, tuple(deltas), tuple(ws), tuple(alphas),
         family=fam, model_idxs=tuple(model_idxs),
         server_lr=float(server_lr), any_stale=any_stale,
-        use_kernel=bool(use_kernel), interpret=interpret,
+        use_kernel=use_kernel, interpret=interpret,
         validate=bool(validate), mag_cap=float(mag_cap))
     return (out, valid) if with_stats else out
 

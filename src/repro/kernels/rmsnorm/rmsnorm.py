@@ -25,9 +25,12 @@ def rmsnorm(x2d, scale, *, eps=1e-5, block_rows=256, interpret=False):
     return pl.pallas_call(
         functools.partial(_kernel, eps=eps),
         grid=(R // block_rows,),
+        # scale travels as [1, d]: a whole-array (1, d) block stays legal
+        # when vmap adds a leading axis (a [d] block would not)
         in_specs=[pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
-                  pl.BlockSpec((d,), lambda i: (0,))],
+                  pl.BlockSpec((1, d), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, d), x2d.dtype),
         interpret=interpret,
-    )(x2d, scale)
+        name="rmsnorm",
+    )(x2d, scale.reshape(1, d))

@@ -15,14 +15,20 @@ from __future__ import annotations
 import jax
 
 
+def _auto(axes):
+    # jax.make_mesh defaults to Explicit axes, which with_sharding_constraint
+    # in the launch steps rejects; these meshes are annotated by hand
+    return (jax.sharding.AxisType.Auto,) * len(axes)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(axes))
 
 
 def make_debug_mesh(*, multi_pod: bool = False):
     """Tiny mesh for in-test dry-runs (requires >=8 host devices)."""
     shape = (2, 2, 2) if multi_pod else (2, 2)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(axes))

@@ -54,37 +54,6 @@ def rmsnorm_init(d: int, dtype):
     return {"scale": jnp.ones((d,), dtype)}
 
 
-def _make_dtype_barrier():
-    # Older jax releases ship optimization_barrier without a differentiation
-    # rule; wrap it in a custom_vjp (the barrier is semantically identity)
-    # so the FL/train grads still work there.
-    barrier = getattr(jax.lax, "optimization_barrier", None)
-    if barrier is None:
-        return lambda x: x
-    try:
-        jax.grad(lambda x: barrier(x * 1.0))(jnp.float32(1))
-        return barrier
-    except Exception:
-        @jax.custom_vjp
-        def _wrapped(x):
-            return barrier(x)
-
-        _wrapped.defvjp(lambda x: (barrier(x), None), lambda _, g: (g,))
-        return _wrapped
-
-
-_dtype_barrier_impl = None
-
-
-def _dtype_barrier(x):
-    # Probe lazily on first use (not at import) so importing the model
-    # package stays free of jax tracing / backend-init side effects.
-    global _dtype_barrier_impl
-    if _dtype_barrier_impl is None:
-        _dtype_barrier_impl = _make_dtype_barrier()
-    return _dtype_barrier_impl(x)
-
-
 def rmsnorm_apply(p: Params, x: jnp.ndarray, eps: float = 1e-5) -> jnp.ndarray:
     xf = x.astype(jnp.float32)
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
@@ -94,7 +63,7 @@ def rmsnorm_apply(p: Params, x: jnp.ndarray, eps: float = 1e-5) -> jnp.ndarray:
     # partitioner's resharding point and the residual-stream all-gathers /
     # all-reduces move FULL-PRECISION tensors (measured 2.8 TB f32/step on
     # yi-34b train_4k; bf16 halves it).  See EXPERIMENTS.md §Perf.
-    return _dtype_barrier(out)
+    return jax.lax.optimization_barrier(out)
 
 
 def layernorm_init(d: int, dtype):

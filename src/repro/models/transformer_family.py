@@ -126,13 +126,8 @@ def _attend(q, k, v):
         blk = _largest_pow2_leq(q.shape[1], 128)
         return flash_attention(q, k, v, causal=True, block_q=blk,
                                block_k=blk)
-    from repro.kernels.flash_attention import attention_ref
-    B, S, H, D = q.shape
-    qb = q.transpose(0, 2, 1, 3).reshape(B * H, S, D)
-    kb = k.transpose(0, 2, 1, 3).reshape(B * H, S, D)
-    vb = v.transpose(0, 2, 1, 3).reshape(B * H, S, D)
-    o = attention_ref(qb, kb, vb, causal=True)
-    return o.reshape(B, H, S, D).transpose(0, 2, 1, 3)
+    from repro.kernels.flash_attention.ops import attention_ref_bshd
+    return attention_ref_bshd(q, k, v, causal=True)
 
 
 # ---------------------------------------------------------------------------

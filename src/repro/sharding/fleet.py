@@ -21,7 +21,8 @@ table cover every model in ``rules.py``.
 Public surface (one-line contracts):
 
 * :data:`FLEET_AXIS` — the mesh-axis name (``"fleet"``).
-* :func:`fleet_mesh` — 1-D Mesh over the local devices (or a prefix).
+* :func:`fleet_mesh` — 1-D Mesh over the local devices (or a prefix;
+  more shards than devices is an error).
 * :func:`fleet_spec_for` — PartitionSpec for one field (rule lookup +
   divisibility fallback).
 * :func:`fleet_shardings` — FleetState-shaped pytree of NamedShardings.
@@ -71,6 +72,9 @@ def fleet_mesh(n_shards: Optional[int] = None, devices=None) -> Mesh:
     ``fleet_mesh=-1``)."""
     devs = list(devices if devices is not None else jax.devices())
     if n_shards is not None and int(n_shards) >= 1:
+        if int(n_shards) > len(devs):
+            raise ValueError(f"fleet mesh of {int(n_shards)} shards asked "
+                             f"for, but only {len(devs)} devices exist")
         devs = devs[:int(n_shards)]
     return Mesh(np.array(devs), (FLEET_AXIS,))
 
@@ -151,12 +155,13 @@ def is_sharded(fleet: FleetState) -> bool:
 
 def maybe_shard_fleet(fleet: FleetState, n_shards: int = 0) -> FleetState:
     """Config-level entry point (``FLConfig.fleet_mesh``): shard over
-    ``min(n_shards, local devices)`` when that is >= 2, otherwise return
-    the fleet unchanged.  ``n_shards <= 1`` (the config default 0) keeps
-    the legacy single-placement fleet — sharding is always opt-in; ``-1``
-    means "all local devices"."""
-    avail = len(jax.devices())
-    want = avail if n_shards == -1 else min(int(n_shards), avail)
+    ``n_shards`` devices when that is >= 2, otherwise return the fleet
+    unchanged.  ``n_shards <= 1`` (the config default 0) keeps the legacy
+    single-placement fleet — sharding is always opt-in; ``-1`` means "all
+    local devices" (unsharded on a one-device runtime).  An explicit
+    request for more shards than devices raises instead of quietly
+    running unsharded."""
+    want = len(jax.devices()) if n_shards == -1 else int(n_shards)
     if want < 2:
         return fleet
     return shard_fleet(fleet, fleet_mesh(want))

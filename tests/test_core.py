@@ -84,10 +84,7 @@ def test_layerwise_aggregate_properties(w1, w2, m_a, m_b):
 def test_fl_allreduce_matches_host_aggregation():
     """Masked psum over a 'pod' axis == layerwise_aggregate (1-device mesh,
     pod size 1 degenerates to identity; also check 1-pod math directly)."""
-    try:
-        from jax import shard_map
-    except ImportError:                      # older jax: experimental only
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     mesh = Mesh(np.array(jax.devices()[:1]), ("pod",))
     u = {"x": jnp.ones((2, 3))}

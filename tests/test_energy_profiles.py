@@ -253,10 +253,16 @@ def test_global_budget_is_a_hard_constraint(selector):
 
 
 def test_budget_exhaustion_terminates_async():
-    cfg = FLConfig(**{**_BASE, "n_rounds": 6, "n_train": 400},
-                   engine_mode="async", global_budget_j=150.0)
+    # the budget is half of what the same run spends unconstrained, so the
+    # joule budget (not the task budget) must be what ends the run
+    base = FLConfig(**{**_BASE, "n_rounds": 6, "n_train": 400},
+                    engine_mode="async", global_budget_j=1e12)
+    free = run_simulation(base, verbose=False)
+    assert free["terminated"]["budget"] == "tasks"
+    limit = 0.5 * free["budget"]["spent"]
+    cfg = dataclasses.replace(base, global_budget_j=limit)
     h = run_simulation(cfg, verbose=False)
-    assert h["budget"]["spent"] <= 150.0 + 1e-6
+    assert h["budget"]["spent"] <= limit + 1e-6
     assert h["terminated"]["reason"] == "budget_exhausted"
     assert h["terminated"]["budget"] == "energy"
 

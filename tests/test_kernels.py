@@ -104,7 +104,7 @@ def test_layer_agg_property(n, l, dpow, seed, zero_col):
 
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-6), (jnp.bfloat16, 2e-2)])
 @pytest.mark.parametrize("shape", [(8, 64), (2, 16, 128), (3, 4, 5, 256),
-                                   # odd row counts: block_rows degrades to 1
+                                   # odd row counts: rows pad to the 8-row tile
                                    (7, 64), (3, 11, 128), (1, 256)])
 def test_rmsnorm_sweep(shape, dtype, tol):
     key = jax.random.PRNGKey(sum(shape))

@@ -39,6 +39,18 @@ def test_maybe_shard_noop_below_two_shards():
     assert not is_sharded(fleet)
 
 
+def test_explicit_mesh_larger_than_device_count_raises():
+    fleet = make_fleet_state(8, seed=0, backend="jax")
+    too_many = len(jax.devices()) + 1
+    with pytest.raises(ValueError, match="devices exist"):
+        maybe_shard_fleet(fleet, too_many)
+    with pytest.raises(ValueError, match="devices exist"):
+        fleet_mesh(too_many)
+    # -1 = all local devices: unsharded on a one-device runtime
+    if len(jax.devices()) == 1:
+        assert maybe_shard_fleet(fleet, -1) is fleet
+
+
 def test_single_device_mesh_spec():
     mesh = fleet_mesh(1)
     # trivially divisible: the fleet axis still names the placement
