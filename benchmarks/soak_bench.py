@@ -70,8 +70,6 @@ def _hist_fingerprint(hist) -> dict:
 
     digests = {}
     for k in sorted(hist):
-        if k == "wall_clock":
-            continue
         blob = json.dumps(canon(hist[k])).encode()
         digests[k] = hashlib.sha256(blob).hexdigest()
     return digests

@@ -29,7 +29,10 @@ Shape discipline: P is padded to the next power of two and T to the next
 power of two of the bucket's longest schedule, so recurring rounds reuse
 the same compiled programs; ``COUNTERS`` tracks logical compilations (new
 shape signatures) and program executions for the dispatch-count regression
-guard in ``tests/test_batch.py``.
+guard in ``tests/test_batch.py``, and the step slots executed and the real
+(unmasked) steps among them, so the padding share is
+``1 - real_steps / slots``.  Each bucket program runs inside a
+``drfl.client_update`` span carrying the same counts (repro.telemetry).
 """
 from __future__ import annotations
 
@@ -41,19 +44,21 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.models.family import resolve_family
 
 # dispatch accounting: "compiles" counts NEW (method, model, shape) program
-# signatures, "executions" counts bucket program launches.  The regression
-# guard asserts <= n_buckets executions per sync round and a bounded
-# compile count across a run.
-COUNTERS = {"compiles": 0, "executions": 0}
+# signatures, "executions" counts bucket program launches, "slots" the
+# P_pad x T_pad client steps those launches executed and "real_steps" the
+# unmasked ones among them.  The regression guard asserts <= n_buckets
+# executions per sync round and a bounded compile count across a run.
+COUNTERS = {"compiles": 0, "executions": 0, "slots": 0, "real_steps": 0}
 _SEEN_SIGNATURES: set = set()
 
 
 def reset_counters() -> None:
-    COUNTERS["compiles"] = 0
-    COUNTERS["executions"] = 0
+    for key in COUNTERS:
+        COUNTERS[key] = 0
     _SEEN_SIGNATURES.clear()
 
 
@@ -230,19 +235,26 @@ def run_bucket(method: str, global_params, x_all, y_all, bucket: Bucket, *,
     if sig not in _SEEN_SIGNATURES:
         _SEEN_SIGNATURES.add(sig)
         COUNTERS["compiles"] += 1
-    COUNTERS["executions"] += 1
-    stacked, losses = _bucket_program(
-        sub, x_all, y_all, jnp.asarray(bucket.gather),
-        jnp.asarray(bucket.valid), method=method, lr=float(lr), family=fam)
     p = bucket.n_real
-    p_pad = bucket.gather.shape[0]
+    p_pad, t_pad = bucket.gather.shape[:2]
+    slots = p_pad * t_pad
+    real_steps = int(np.count_nonzero(bucket.valid))
+    COUNTERS["executions"] += 1
+    COUNTERS["slots"] += slots
+    COUNTERS["real_steps"] += real_steps
+    with telemetry.span("client_update", real_steps=real_steps,
+                        slots=slots):
+        stacked, losses = _bucket_program(
+            sub, x_all, y_all, jnp.asarray(bucket.gather),
+            jnp.asarray(bucket.valid), method=method, lr=float(lr),
+            family=fam)
+        # jaxlint: allow(host-sync-in-hot-path) -- one losses pull per bucket program; deltas stay on device
+        losses = np.asarray(losses[:p])
     return BucketResult(model_idx=bucket.model_idx,
                         participants=list(bucket.participants),
                         weights=(list(bucket.weights)
                                  + [0.0] * (p_pad - p)),
-                        stacked_delta=stacked,
-                        # jaxlint: allow(host-sync-in-hot-path) -- one losses pull per bucket program; deltas stay on device
-                        losses=np.asarray(losses[:p]))
+                        stacked_delta=stacked, losses=losses)
 
 
 # ---------------------------------------------------------------------------
@@ -278,8 +290,9 @@ def run_cohort(method: str, global_params, x_all, y_all,
     zero-data participants must already be filtered out."""
     if weights is None:
         weights = [float(len(p)) for p in parts]
-    buckets = bucket_cohort(participants, model_idxs, parts, seeds, weights,
-                            epochs=epochs, batch=batch)
+    with telemetry.span("bucket_schedule"):
+        buckets = bucket_cohort(participants, model_idxs, parts, seeds,
+                                weights, epochs=epochs, batch=batch)
     x_all = jnp.asarray(x_all)
     y_all = jnp.asarray(y_all)
     fam = resolve_family(family)

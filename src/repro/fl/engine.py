@@ -53,13 +53,13 @@ from __future__ import annotations
 
 import dataclasses
 import heapq
-import time
 from typing import Any, Dict, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.checkpoint.engine import (CheckpointHalt, EngineCheckpointer,
                                      config_fingerprint)
 from repro.checkpoint.io import FLEET_CHECKPOINT_FIELDS
@@ -279,13 +279,14 @@ def _marl_train(marl, buffer, hist, fleet, round_idx, n_updates):
     Call order (episode_arrays → add_episode → sample/update loop) is
     byte-identical to the legacy inline blocks — the buffer RNG consumes
     the same draws, keeping sync parity with the frozen reference."""
-    obs, state, actions, rewards = marl.episode_arrays(fleet, round_idx)
-    buffer.add_episode(obs, state, actions, rewards)
-    losses = []
-    for _ in range(n_updates):
-        batch = buffer.sample(marl.learner.cfg.batch_size)
-        if batch:
-            losses.append(marl.learner.update(batch)["td_loss"])
+    with telemetry.span("qmix"):
+        obs, state, actions, rewards = marl.episode_arrays(fleet, round_idx)
+        buffer.add_episode(obs, state, actions, rewards)
+        losses = []
+        for _ in range(n_updates):
+            batch = buffer.sample(marl.learner.cfg.batch_size)
+            if batch:
+                losses.append(marl.learner.update(batch)["td_loss"])
     q = hist.setdefault("qmix", {
         "mixer_mode": marl.mixer_mode,
         "replay_capacity": buffer.capacity,
@@ -298,6 +299,16 @@ def _marl_train(marl, buffer, hist, fleet, round_idx, n_updates):
     q["replay_episodes"] = len(buffer)
     q["updates"] = marl.learner.updates
     q["td_loss"].extend(losses)
+
+
+def _upgrade_hist(hist: dict) -> dict:
+    """A history saved by an older release, under today's keys: the
+    straggler wait ``idle`` is ``straggler_wait_s``, and ``wall_clock``
+    (host seconds per round) is no longer kept."""
+    if "idle" in hist:
+        hist["straggler_wait_s"] = hist.pop("idle")
+    hist.pop("wall_clock", None)
+    return hist
 
 
 class RoundEngine:
@@ -522,7 +533,7 @@ class RoundEngine:
         if rs is None:
             hist = {"acc": [], "acc_mean": [], "energy": [], "round_time": [],
                     "alive": [], "participants": [], "model_choices": [],
-                    "reward": [], "wall_clock": [], "sim_time": [], "idle": [],
+                    "reward": [], "sim_time": [], "straggler_wait_s": [],
                     "dropouts": 0, "idle_time": 0.0, "engine": "sync",
                     "faults": {"events": [], "quarantined": [],
                                "n_reaped": 0, "n_quarantined": 0}}
@@ -538,7 +549,7 @@ class RoundEngine:
         else:
             fleet = self._restore_fleet(fleet, rs["fleet"])
             global_params = rs["global_params"]
-            hist = rs["hist"]
+            hist = _upgrade_hist(rs["hist"])
             prev_acc = float(rs["prev_acc"])  # jaxlint: allow(host-sync-in-hot-path) -- one-time resume, host values
             e_prev = float(rs["e_prev"])  # jaxlint: allow(host-sync-in-hot-path) -- one-time resume, host values
             sim_time = float(rs["sim_time"])  # jaxlint: allow(host-sync-in-hot-path) -- one-time resume, host values
@@ -553,237 +564,256 @@ class RoundEngine:
         budget_exhausted = False
 
         for t in range(t_start, cfg.n_rounds):
-            t0 = time.time()
-            if (cfg.hotplug_n and not hotplug_done
-                    and t >= cfg.hotplug_round):
-                # paper Step 1 hot-plug: new devices connect, receive the
-                # global model (implicit — clients always pull W_t), start
-                # with full batteries
-                fleet = fleet_connect(fleet, cfg.n_devices, cfg.energy_scale)
-                hotplug_done = True
-                if alive_host is not None:
-                    alive_host[cfg.n_devices:] = True
-            # Top-K budget tracks the CONNECTED fleet (see ISSUE 1 fix).
-            n_connected = cfg.n_devices + (cfg.hotplug_n if hotplug_done
-                                           else 0)
-            k = max(1, int(round(cfg.participation * n_connected)))
-            sel_fleet = fleet
-            if gate_avail:
-                # diurnal/carbon gate: offline devices look dead to the
-                # selector this round (they auto-abstain, PR 2 semantics)
-                av_host = scenario.available_host(tz_host, sim_time)
-                if alive_host.any() and not (av_host & alive_host).any():
-                    # whole surviving fleet is offline — fast-forward the
-                    # clock to the next opening instead of burning rounds
-                    sim_time = scenario.next_available_host(
-                        tz_host[alive_host], sim_time)
-                av_d = scenario.available(fleet, sim_time)
-                sel_fleet = fleet.replace(alive=fleet.alive & av_d)
-            sel_kw = {}
-            budget_left = overrun = 0.0
-            if budget_active:
-                # per-pick hard cap: selectors refuse actions whose cost
-                # alone no longer fits the remaining fleet-wide budget
-                budget_left = float(cfg.global_budget_j) - budget_spent
-                sel_kw["budget_left"] = budget_left
-            sel = selector.select(sel_fleet, t, k, w.sizes, w.fractions,
-                                  cfg.local_epochs, cfg.batch_size, **sel_kw)
-            _check_selection(sel, w.n_total)
+            with telemetry.round_span(t):
+                if (cfg.hotplug_n and not hotplug_done
+                        and t >= cfg.hotplug_round):
+                    # paper Step 1 hot-plug: new devices connect, receive
+                    # the global model (implicit — clients always pull W_t),
+                    # start with full batteries
+                    fleet = fleet_connect(fleet, cfg.n_devices,
+                                          cfg.energy_scale)
+                    hotplug_done = True
+                    if alive_host is not None:
+                        alive_host[cfg.n_devices:] = True
+                # Top-K budget tracks the CONNECTED fleet (see ISSUE 1 fix).
+                n_connected = cfg.n_devices + (cfg.hotplug_n if hotplug_done
+                                               else 0)
+                k = max(1, int(round(cfg.participation * n_connected)))
+                sel_fleet = fleet
+                if gate_avail:
+                    # diurnal/carbon gate: offline devices look dead to the
+                    # selector this round (they auto-abstain, PR 2 semantics)
+                    av_host = scenario.available_host(tz_host, sim_time)
+                    if alive_host.any() and not (av_host & alive_host).any():
+                        # whole surviving fleet is offline — fast-forward the
+                        # clock to the next opening instead of burning rounds
+                        sim_time = scenario.next_available_host(
+                            tz_host[alive_host], sim_time)
+                    av_d = scenario.available(fleet, sim_time)
+                    sel_fleet = fleet.replace(alive=fleet.alive & av_d)
+                sel_kw = {}
+                budget_left = overrun = 0.0
+                if budget_active:
+                    # per-pick hard cap: selectors refuse actions whose cost
+                    # alone no longer fits the remaining fleet-wide budget
+                    budget_left = float(cfg.global_budget_j) - budget_spent
+                    sel_kw["budget_left"] = budget_left
+                with telemetry.span("select"):
+                    sel = selector.select(sel_fleet, t, k, w.sizes,
+                                          w.fractions, cfg.local_epochs,
+                                          cfg.batch_size, **sel_kw)
+                _check_selection(sel, w.n_total)
 
-            choice = np.asarray(sel.model_choice, np.int64)
-            active = choice >= 0
-            m_idx = np.clip(choice, 0, M - 1)
-            t_tra_m, t_com_m, e_tra_m, e_com_m = fleet_cost_matrix_jit(
-                fleet, w.sizes, w.fractions, cfg.local_epochs, cfg.batch_size)
-            # gather each device's chosen-model column on device, charge,
-            # then pull everything the round head needs in ONE sync
-            m_col = jnp.asarray(m_idx)[:, None]
-            t_cost_d = jnp.take_along_axis(t_tra_m + t_com_m, m_col, 1)[:, 0]
-            need_d = jnp.take_along_axis(e_tra_m + e_com_m, m_col, 1)[:, 0]
-            had_picks = bool(active.any())
-            budget_starved = False
-            if budget_active and not had_picks:
-                # no picks at all: decide whether the per-pick budget gate
-                # (not drained batteries) closed the round — if some alive
-                # device could fund its cheapest submodel from its OWN
-                # battery but not from the remaining global budget, further
-                # rounds can never dispatch either
-                # jaxlint: allow(host-sync-in-hot-path) -- budget-scenario termination disambiguation; runs only when a round selects nobody
-                mn_a, rem_a, al_a = jax.device_get(
-                    ((e_tra_m + e_com_m).min(axis=1), fleet.remaining,
-                     fleet.alive))
-                mn = np.asarray(mn_a, np.float64)
-                own_ok = (np.asarray(al_a, bool)
-                          & (mn < np.asarray(rem_a, np.float64)))
-                if own_ok.any() and mn[own_ok].min() > budget_left:
-                    budget_starved = True
-            if budget_active:
-                # cumulative hard cap: each pick respected the per-pick
-                # budget gate, but together they can still overrun — trim
-                # in selection order and charge the trimmed cost to the
-                # round's reward as an overrun penalty
-                # jaxlint: allow(host-sync-in-hot-path) -- budget-scenario-only extra pull: per-pick costs for the cumulative cap
-                need_h = np.asarray(jax.device_get(need_d), np.float64)
-                left = budget_left
-                for i in sel.participants:
-                    if not active[i]:
-                        continue
-                    if need_h[i] <= left + 1e-9:
-                        left -= float(need_h[i])
+                choice = np.asarray(sel.model_choice, np.int64)
+                active = choice >= 0
+                m_idx = np.clip(choice, 0, M - 1)
+                with telemetry.span("charge"):
+                    t_tra_m, t_com_m, e_tra_m, e_com_m = (
+                        fleet_cost_matrix_jit(fleet, w.sizes, w.fractions,
+                                              cfg.local_epochs,
+                                              cfg.batch_size))
+                    # gather each device's chosen-model column on device,
+                    # charge, then pull everything the round head needs in
+                    # ONE sync
+                    m_col = jnp.asarray(m_idx)[:, None]
+                    t_cost_d = jnp.take_along_axis(t_tra_m + t_com_m, m_col,
+                                                   1)[:, 0]
+                    need_d = jnp.take_along_axis(e_tra_m + e_com_m, m_col,
+                                                 1)[:, 0]
+                    had_picks = bool(active.any())
+                    budget_starved = False
+                    if budget_active and not had_picks:
+                        # no picks at all: decide whether the per-pick budget
+                        # gate (not drained batteries) closed the round — if
+                        # some alive device could fund its cheapest submodel
+                        # from its OWN battery but not from the remaining
+                        # global budget, further rounds can never dispatch
+                        # either
+                        # jaxlint: allow(host-sync-in-hot-path) -- budget-scenario termination disambiguation; runs only when a round selects nobody
+                        mn_a, rem_a, al_a = jax.device_get(
+                            ((e_tra_m + e_com_m).min(axis=1), fleet.remaining,
+                             fleet.alive))
+                        mn = np.asarray(mn_a, np.float64)
+                        own_ok = (np.asarray(al_a, bool)
+                                  & (mn < np.asarray(rem_a, np.float64)))
+                        if own_ok.any() and mn[own_ok].min() > budget_left:
+                            budget_starved = True
+                    if budget_active:
+                        # cumulative hard cap: each pick respected the
+                        # per-pick budget gate, but together they can still
+                        # overrun — trim in selection order and charge the
+                        # trimmed cost to the round's reward as an overrun
+                        # penalty
+                        # jaxlint: allow(host-sync-in-hot-path) -- budget-scenario-only extra pull: per-pick costs for the cumulative cap
+                        need_h = np.asarray(jax.device_get(need_d),
+                                            np.float64)
+                        left = budget_left
+                        for i in sel.participants:
+                            if not active[i]:
+                                continue
+                            if need_h[i] <= left + 1e-9:
+                                left -= float(need_h[i])
+                            else:
+                                active[i] = False
+                                overrun += float(need_h[i])
+                        # attempted cost counts as spent (deaths waste no
+                        # more than their attempt), so the cap can never be
+                        # overdrawn
+                        budget_spent += float(need_h[active].sum())
+                    fleet, ok_d = fleet_charge_jit(fleet, need_d,
+                                                   jnp.asarray(active))
+                    # jaxlint: allow(host-sync-in-hot-path) -- the one batched pull per round head: charge outcome + per-device round times
+                    t_cost, ok = jax.device_get((t_cost_d, ok_d))
+                hist["dropouts"] += int((active & ~ok).sum())
+                survivors = active & ok
+                t_round = (float(t_cost[survivors].max())
+                           if survivors.any() else 0.0)
+                # straggler wait: finished participants idle at the barrier
+                idle_round = float((t_round - t_cost[survivors]).sum())
+                if recharge and t_round > 0.0:
+                    # harvesting: alive devices trickle-charge while the
+                    # round runs (midpoint-rate rectangle over
+                    # [sim_time, +t_round])
+                    fleet = scenario.apply_charge(fleet, sim_time,
+                                                  sim_time + t_round)
+
+                # contributors: survivors with local data (large-fleet
+                # Dirichlet splits can leave a device with no samples — it
+                # still paid the round's mostly-comm energy but has nothing
+                # to contribute)
+                cohort = [i for i in sel.participants
+                          if survivors[i] and len(w.parts[i])]
+                if self.executor == "batched" and cohort:
+                    # whole cohort in <= n_buckets jit dispatches (one per
+                    # populated submodel index), stacked deltas straight
+                    # into the Pallas layer-agg aggregation for DR-FL
+                    res = _run_batched_cohort(
+                        cfg, w, global_params, cohort,
+                        [int(choice[i]) for i in cohort],
+                        [fl_client.client_update_seed(cfg.seed, t, i)
+                         for i in cohort], x_dev, y_dev)
+                    if cfg.method == "drfl":
+                        global_params, valid = (
+                            fl_server.aggregate_drfl_stacked(
+                                global_params,
+                                [(b.model_idx, b.stacked_delta, b.weights,
+                                  None) for b in res.buckets],
+                                server_lr=cfg.server_lr, family=w.family,
+                                with_stats=True))
+                        devs, models = [], []
+                        for b in res.buckets:
+                            pad = len(b.weights) - len(b.participants)
+                            devs += list(b.participants) + [None] * pad
+                            models += [b.model_idx] * len(b.weights)
+                        if valid is not None:
+                            self._qpend.append((
+                                {"devices": devs, "models": models,
+                                 "round": t, "time": sim_time}, valid))
                     else:
-                        active[i] = False
-                        overrun += float(need_h[i])
-                # attempted cost counts as spent (deaths waste no more than
-                # their attempt), so the cap can never be overdrawn
-                budget_spent += float(need_h[active].sum())
-            fleet, ok_d = fleet_charge_jit(fleet, need_d,
-                                           jnp.asarray(active))
-            # jaxlint: allow(host-sync-in-hot-path) -- the one batched pull per round head: charge outcome + per-device round times
-            t_cost, ok = jax.device_get((t_cost_d, ok_d))
-            hist["dropouts"] += int((active & ~ok).sum())
-            survivors = active & ok
-            t_round = float(t_cost[survivors].max()) if survivors.any() else 0.0
-            # straggler wait: finished participants idle at the barrier
-            idle_round = float((t_round - t_cost[survivors]).sum())
-            if recharge and t_round > 0.0:
-                # harvesting: alive devices trickle-charge while the round
-                # runs (midpoint-rate rectangle over [sim_time, +t_round])
-                fleet = scenario.apply_charge(fleet, sim_time,
-                                              sim_time + t_round)
-
-            # contributors: survivors with local data (large-fleet Dirichlet
-            # splits can leave a device with no samples — it still paid the
-            # round's mostly-comm energy but has nothing to contribute)
-            cohort = [i for i in sel.participants
-                      if survivors[i] and len(w.parts[i])]
-            if self.executor == "batched" and cohort:
-                # whole cohort in <= n_buckets jit dispatches (one per
-                # populated submodel index), stacked deltas straight into
-                # the Pallas layer-agg aggregation for DR-FL
-                res = _run_batched_cohort(
-                    cfg, w, global_params, cohort,
-                    [int(choice[i]) for i in cohort],
-                    [fl_client.client_update_seed(cfg.seed, t, i)
-                     for i in cohort], x_dev, y_dev)
-                if cfg.method == "drfl":
-                    global_params, valid = fl_server.aggregate_drfl_stacked(
-                        global_params,
-                        [(b.model_idx, b.stacked_delta, b.weights, None)
-                         for b in res.buckets], server_lr=cfg.server_lr,
-                        family=w.family, with_stats=True)
-                    devs, models = [], []
-                    for b in res.buckets:
-                        pad = len(b.weights) - len(b.participants)
-                        devs += list(b.participants) + [None] * pad
-                        models += [b.model_idx] * len(b.weights)
-                    if valid is not None:
+                        contribs = res.unstacked()
+                        global_params, valid = fl_server.aggregate_sliced(
+                            global_params, [c[2] for c in contribs],
+                            [c[3] for c in contribs], with_stats=True)
                         self._qpend.append((
-                            {"devices": devs, "models": models, "round": t,
+                            {"devices": [c[0] for c in contribs],
+                             "models": [c[1] for c in contribs], "round": t,
                              "time": sim_time}, valid))
-                else:
-                    contribs = res.unstacked()
-                    global_params, valid = fl_server.aggregate_sliced(
-                        global_params, [c[2] for c in contribs],
-                        [c[3] for c in contribs], with_stats=True)
+                    n_agg += 1
+                elif cohort:
+                    deltas, idxs, weights = [], [], []
+                    for i in cohort:
+                        m = int(choice[i])
+                        xi = w.x_tr[w.parts[i]]
+                        yi = w.y_tr[w.parts[i]]
+                        upd_seed = fl_client.client_update_seed(cfg.seed, t,
+                                                                i)
+                        d_, _ = _client_update(cfg, w.family, global_params,
+                                               m, xi, yi, upd_seed)
+                        deltas.append(d_)
+                        idxs.append(m)
+                        weights.append(float(len(xi)))
+                    if cfg.method == "drfl":
+                        global_params, valid = fl_server.aggregate_drfl(
+                            global_params, deltas, idxs, weights,
+                            server_lr=cfg.server_lr, family=w.family,
+                            with_stats=True)
+                    else:
+                        global_params, valid = fl_server.aggregate_sliced(
+                            global_params, deltas, weights, with_stats=True)
                     self._qpend.append((
-                        {"devices": [c[0] for c in contribs],
-                         "models": [c[1] for c in contribs], "round": t,
-                         "time": sim_time}, valid))
-                n_agg += 1
-            elif cohort:
-                deltas, idxs, weights = [], [], []
-                for i in cohort:
-                    m = int(choice[i])
-                    xi = w.x_tr[w.parts[i]]
-                    yi = w.y_tr[w.parts[i]]
-                    upd_seed = fl_client.client_update_seed(cfg.seed, t, i)
-                    d_, _ = _client_update(cfg, w.family, global_params, m,
-                                           xi, yi, upd_seed)
-                    deltas.append(d_)
-                    idxs.append(m)
-                    weights.append(float(len(xi)))
-                if cfg.method == "drfl":
-                    global_params, valid = fl_server.aggregate_drfl(
-                        global_params, deltas, idxs, weights,
-                        server_lr=cfg.server_lr, family=w.family,
-                        with_stats=True)
-                else:
-                    global_params, valid = fl_server.aggregate_sliced(
-                        global_params, deltas, weights, with_stats=True)
-                self._qpend.append((
-                    {"devices": list(cohort), "models": idxs, "round": t,
-                     "time": sim_time}, valid))
-                n_agg += 1
+                        {"devices": list(cohort), "models": idxs,
+                         "round": t, "time": sim_time}, valid))
+                    n_agg += 1
 
-            accs = fl_server.evaluate(global_params, w.x_val, w.y_val,
-                                      family=w.family)
-            acc = float(np.mean(accs))
-            # jaxlint: allow(host-sync-in-hot-path) -- one batched pull per round tail: reward energy term + alive telemetry
-            e_now_a, alive_a = jax.device_get((fleet.remaining.sum(),
-                                               fleet.alive))
-            e_now = float(e_now_a)
-            reward = (w1 * (acc - prev_acc) - w2 * (e_prev - e_now)
-                      - w3 * (t_round / 60.0))
-            if budget_active and overrun:
-                # budget-overrun penalty: energy the fleet PROPOSED to spend
-                # past the global cap, priced like wasted joules
-                reward -= w2 * overrun
-            sim_time += t_round
-            selector.observe_reward(reward, sim_time=sim_time)
-            prev_acc, e_prev = acc, e_now
+                accs = fl_server.evaluate(global_params, w.x_val, w.y_val,
+                                          family=w.family)
+                acc = float(np.mean(accs))
+                # jaxlint: allow(host-sync-in-hot-path) -- one batched pull per round tail: reward energy term + alive telemetry
+                e_now_a, alive_a = jax.device_get((fleet.remaining.sum(),
+                                                   fleet.alive))
+                e_now = float(e_now_a)
+                reward = (w1 * (acc - prev_acc) - w2 * (e_prev - e_now)
+                          - w3 * (t_round / 60.0))
+                if budget_active and overrun:
+                    # budget-overrun penalty: energy the fleet PROPOSED to
+                    # spend past the global cap, priced like wasted joules
+                    reward -= w2 * overrun
+                sim_time += t_round
+                selector.observe_reward(reward, sim_time=sim_time)
+                prev_acc, e_prev = acc, e_now
 
-            if marl:
-                if (t + 1) % cfg.marl_train_every == 0 and marl.ep_rewards:
-                    _marl_train(marl, buffer, hist, fleet, t + 1,
-                                cfg.marl_updates_per_round)
+                if marl:
+                    if ((t + 1) % cfg.marl_train_every == 0
+                            and marl.ep_rewards):
+                        _marl_train(marl, buffer, hist, fleet, t + 1,
+                                    cfg.marl_updates_per_round)
 
-            alive_now = int(alive_a.sum())
-            hist["acc"].append(np.asarray(accs))
-            hist["acc_mean"].append(acc)
-            hist["energy"].append(e_now)
-            hist["round_time"].append(t_round)
-            hist["alive"].append(alive_now)
-            hist["participants"].append(list(sel.participants))
-            hist["model_choices"].append(
-                [sel.model_choice[i] for i in sel.participants])
-            hist["reward"].append(reward)
-            hist["wall_clock"].append(time.time() - t0)
-            hist["sim_time"].append(sim_time)
-            hist["idle"].append(idle_round)
-            hist["idle_time"] += idle_round
-            if alive_host is not None:
-                alive_host = np.asarray(alive_a, bool).copy()
-            if budget_active:
-                hist["budget"]["spent"] = budget_spent
-                hist["budget"]["overrun"] += overrun
-                if overrun:
-                    hist["budget"]["trimmed"] += 1
-            if self.verbose:
-                print(f"  round {t:3d}: acc={acc:.3f} exits="
-                      f"{np.round(np.asarray(accs), 3)} alive={alive_now}"
-                      f" energy={e_now:,.0f}J time={t_round:.1f}s"
-                      f" r={reward:+.2f}")
-            if alive_now == 0:
-                fleet_dead = True
-                break
-            if budget_active and (
-                    float(cfg.global_budget_j) - budget_spent <= 1e-9
-                    or budget_starved
-                    or (had_picks and not active.any())):
-                # nothing left to fund (or the whole round's picks were
-                # trimmed): stop here rather than ticking unfunded rounds
-                budget_exhausted = True
-                break
-            if (self.ckpt is not None and self.ckpt_every > 0
-                    and (t + 1) % self.ckpt_every == 0):
-                self._flush_quarantine(hist)
-                state = self._base_snapshot(fleet, global_params, hist)
-                state.update(next_round=t + 1, prev_acc=prev_acc,
-                             e_prev=e_prev, sim_time=sim_time, n_agg=n_agg,
-                             hotplug_done=hotplug_done,
-                             budget_spent=budget_spent)
-                self.ckpt.save(state, self._ckpt_meta(t + 1))
-                self._after_save()
+                alive_now = int(alive_a.sum())
+                hist["acc"].append(np.asarray(accs))
+                hist["acc_mean"].append(acc)
+                hist["energy"].append(e_now)
+                hist["round_time"].append(t_round)
+                hist["alive"].append(alive_now)
+                hist["participants"].append(list(sel.participants))
+                hist["model_choices"].append(
+                    [sel.model_choice[i] for i in sel.participants])
+                hist["reward"].append(reward)
+                hist["sim_time"].append(sim_time)
+                hist["straggler_wait_s"].append(idle_round)
+                hist["idle_time"] += idle_round
+                if alive_host is not None:
+                    alive_host = np.asarray(alive_a, bool).copy()
+                if budget_active:
+                    hist["budget"]["spent"] = budget_spent
+                    hist["budget"]["overrun"] += overrun
+                    if overrun:
+                        hist["budget"]["trimmed"] += 1
+                if self.verbose:
+                    print(f"  round {t:3d}: acc={acc:.3f} exits="
+                          f"{np.round(np.asarray(accs), 3)} alive={alive_now}"
+                          f" energy={e_now:,.0f}J time={t_round:.1f}s"
+                          f" r={reward:+.2f}")
+                if alive_now == 0:
+                    fleet_dead = True
+                    break
+                if budget_active and (
+                        float(cfg.global_budget_j) - budget_spent <= 1e-9
+                        or budget_starved
+                        or (had_picks and not active.any())):
+                    # nothing left to fund (or the whole round's picks were
+                    # trimmed): stop here rather than ticking unfunded rounds
+                    budget_exhausted = True
+                    break
+                if (self.ckpt is not None and self.ckpt_every > 0
+                        and (t + 1) % self.ckpt_every == 0):
+                    self._flush_quarantine(hist)
+                    state = self._base_snapshot(fleet, global_params, hist)
+                    state.update(next_round=t + 1, prev_acc=prev_acc,
+                                 e_prev=e_prev, sim_time=sim_time, n_agg=n_agg,
+                                 hotplug_done=hotplug_done,
+                                 budget_spent=budget_spent)
+                    self.ckpt.save(state, self._ckpt_meta(t + 1))
+                    self._after_save()
 
         hist["terminated"] = {
             "reason": ("budget_exhausted" if budget_exhausted
@@ -843,7 +873,7 @@ class RoundEngine:
         if rs is None:
             hist = {"acc": [], "acc_mean": [], "energy": [], "round_time": [],
                     "alive": [], "participants": [], "model_choices": [],
-                    "reward": [], "wall_clock": [], "sim_time": [], "idle": [],
+                    "reward": [], "sim_time": [], "straggler_wait_s": [],
                     "staleness": [], "task_log": [], "lost": [],
                     "dropouts": 0, "idle_time": 0.0, "wait_for_work": 0.0,
                     "hotplug": None, "engine": "async",
@@ -857,8 +887,7 @@ class RoundEngine:
                          tasks_started=0, completions=0, inflight=0,
                          n_cohorts=0, next_commit=0, last_event=0.0,
                          hotplug_done=not cfg.hotplug_n, acc_prev=acc_prev,
-                         window_t0=0.0, window_wall0=time.time(),
-                         window_reward=0.0, window_idle=0.0,
+                         window_t0=0.0, window_reward=0.0, window_idle=0.0,
                          window_lost=0, tid=0,
                          budget_spent=0.0, budget_blocked=False,
                          last_charge_t=0.0)
@@ -891,9 +920,9 @@ class RoundEngine:
         else:
             fleet = self._restore_fleet(fleet, rs["fleet"])
             global_params = rs["global_params"]
-            hist = rs["hist"]
+            hist = _upgrade_hist(rs["hist"])
             state = dict(rs["state"])
-            state["window_wall0"] = time.time()
+            state.pop("window_wall0", None)
             state.setdefault("budget_spent", 0.0)
             state.setdefault("budget_blocked", False)
             state.setdefault("last_charge_t", float(state["now"]))
@@ -995,30 +1024,34 @@ class RoundEngine:
             cohorts[cid] = {"pending": 0, "reward": 0.0}
             alive_mask = (jnp.asarray(idle) if fleet_is_jax(fleet) else idle)
             sel_kw = {"budget_left": budget_left} if budget_active else {}
-            sel = selector.select(fleet.replace(alive=alive_mask),
-                                  state["vround"], n_sel, w.sizes,
-                                  w.fractions, cfg.local_epochs,
-                                  cfg.batch_size, **sel_kw)
+            with telemetry.span("select"):
+                sel = selector.select(fleet.replace(alive=alive_mask),
+                                      state["vround"], n_sel, w.sizes,
+                                      w.fractions, cfg.local_epochs,
+                                      cfg.batch_size, **sel_kw)
             _check_selection(sel, w.n_total)
             choice = np.asarray(sel.model_choice, np.int64)
             active = choice >= 0
             if active.any():
                 m_idx = np.clip(choice, 0, w.n_models - 1)
-                t_tra, t_com, e_tra, e_com = fleet_cost_matrix_jit(
-                    fleet, w.sizes, w.fractions, cfg.local_epochs,
-                    cfg.batch_size)
-                m_col = jnp.asarray(m_idx)[:, None]
-                need_d = jnp.take_along_axis(e_tra + e_com, m_col,
-                                             1)[:, 0]
-                t_cost_d = jnp.take_along_axis(t_tra + t_com, m_col, 1)[:, 0]
-                need_h = None
-                if budget_active:
-                    # jaxlint: allow(host-sync-in-hot-path) -- budget-scenario variant of the same first batched pull (extra values, same sync count)
-                    t_cost, need_h = jax.device_get((t_cost_d, need_d))
-                    need_h = np.asarray(need_h, np.float64)
-                else:
-                    # jaxlint: allow(host-sync-in-hot-path) -- first of the two batched pulls per dispatch tick: per-task times for the event heap
-                    t_cost = jax.device_get(t_cost_d)
+                # the charge is two spans: costs here, the charge below
+                with telemetry.span("charge"):
+                    t_tra, t_com, e_tra, e_com = fleet_cost_matrix_jit(
+                        fleet, w.sizes, w.fractions, cfg.local_epochs,
+                        cfg.batch_size)
+                    m_col = jnp.asarray(m_idx)[:, None]
+                    need_d = jnp.take_along_axis(e_tra + e_com, m_col,
+                                                 1)[:, 0]
+                    t_cost_d = jnp.take_along_axis(t_tra + t_com, m_col,
+                                                   1)[:, 0]
+                    need_h = None
+                    if budget_active:
+                        # jaxlint: allow(host-sync-in-hot-path) -- budget-scenario variant of the same first batched pull (extra values, same sync count)
+                        t_cost, need_h = jax.device_get((t_cost_d, need_d))
+                        need_h = np.asarray(need_h, np.float64)
+                    else:
+                        # jaxlint: allow(host-sync-in-hot-path) -- first of the two batched pulls per dispatch tick: per-task times for the event heap
+                        t_cost = jax.device_get(t_cost_d)
                 if horizon > 0:
                     # only send work that can land inside the time budget
                     active &= (now + t_cost) <= horizon + 1e-9
@@ -1060,12 +1093,13 @@ class RoundEngine:
                     if own_ok.any() and min_need[own_ok].min() > budget_left:
                         state["budget_blocked"] = True
                 return 0
-            e_before_d = fleet.remaining.sum()
-            fleet, ok_d = fleet_charge_jit(fleet, need_d,
-                                           jnp.asarray(active))
-            # jaxlint: allow(host-sync-in-hot-path) -- second batched pull per dispatch tick: charge outcome + energy reward terms
-            ok, e_before_a, e_after_a = jax.device_get(
-                (ok_d, e_before_d, fleet.remaining.sum()))
+            with telemetry.span("charge"):
+                e_before_d = fleet.remaining.sum()
+                fleet, ok_d = fleet_charge_jit(fleet, need_d,
+                                               jnp.asarray(active))
+                # jaxlint: allow(host-sync-in-hot-path) -- second batched pull per dispatch tick: charge outcome + energy reward terms
+                ok, e_before_a, e_after_a = jax.device_get(
+                    (ok_d, e_before_d, fleet.remaining.sum()))
             e_before, e_after = float(e_before_a), float(e_after_a)
             # fleet_charge kills attempted-but-unaffordable devices; fold
             # the same deaths into the host mirror
@@ -1169,9 +1203,8 @@ class RoundEngine:
             hist["participants"].append(list(window_devices))
             hist["model_choices"].append(list(window_models))
             hist["reward"].append(state["window_reward"])
-            hist["wall_clock"].append(time.time() - state["window_wall0"])
             hist["sim_time"].append(now)
-            hist["idle"].append(state["window_idle"])
+            hist["straggler_wait_s"].append(state["window_idle"])
             hist["lost"].append(state["window_lost"])
             if self.verbose:
                 print(f"  vround {state['vround']:3d}: acc={acc:.3f}"
@@ -1180,7 +1213,6 @@ class RoundEngine:
             window_devices.clear()
             window_models.clear()
             state["window_t0"] = now
-            state["window_wall0"] = time.time()
             state["window_reward"] = 0.0
             state["window_idle"] = 0.0
             state["window_lost"] = 0

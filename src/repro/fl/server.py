@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.core import aggregation
 from repro.core.aggregation import (DELTA_MAG_CAP, delta_valid,
                                     layerwise_aggregate, sanitize_delta,
@@ -39,12 +40,13 @@ def evaluate(params, x_val: np.ndarray, y_val: np.ndarray,
     """Per-exit accuracy on the server validation set."""
     eval_batch = resolve_family(family).eval_fn()
     accs, n = [], 0
-    for i in range(0, len(x_val), batch):
-        xb = jnp.asarray(x_val[i:i + batch])
-        yb = jnp.asarray(y_val[i:i + batch])
-        # jaxlint: allow(host-sync-in-hot-path) -- one pull per eval batch; evaluate returns host accuracies by contract
-        accs.append(np.asarray(eval_batch(params, xb, yb)) * len(xb))
-        n += len(xb)
+    with telemetry.span("eval"):
+        for i in range(0, len(x_val), batch):
+            xb = jnp.asarray(x_val[i:i + batch])
+            yb = jnp.asarray(y_val[i:i + batch])
+            # jaxlint: allow(host-sync-in-hot-path) -- one pull per eval batch; evaluate returns host accuracies by contract
+            accs.append(np.asarray(eval_batch(params, xb, yb)) * len(xb))
+            n += len(xb)
     return np.sum(accs, axis=0) / max(n, 1)
 
 
@@ -205,27 +207,28 @@ def aggregate_drfl_stacked(global_params, buckets, server_lr: float = 1.0,
     ``with_stats`` returns ``(params, valid)`` with the [N_total] row
     validity left ON DEVICE — callers batch the pull."""
     fam = resolve_family(family)
-    model_idxs, deltas, ws, alphas = [], [], [], []
-    any_stale = False
-    for model_idx, delta, weights, stal in buckets:
-        p = len(weights)
-        model_idxs.append(int(model_idx))
-        deltas.append(delta)
-        ws.append(jnp.asarray([float(x) for x in weights], jnp.float32))
-        if stal is None:
-            alphas.append(jnp.ones((p,), jnp.float32))
-        else:
-            scales = [staleness_scale(s, staleness_decay) for s in stal]
-            any_stale = any_stale or any(a != 1.0 for a in scales)
-            alphas.append(jnp.asarray(scales, jnp.float32))
-    if not deltas:
-        return (global_params, None) if with_stats else global_params
-    out, valid = _stacked_agg_program(
-        global_params, tuple(deltas), tuple(ws), tuple(alphas),
-        family=fam, model_idxs=tuple(model_idxs),
-        server_lr=float(server_lr), any_stale=any_stale,
-        use_kernel=use_kernel, interpret=interpret,
-        validate=bool(validate), mag_cap=float(mag_cap))
+    with telemetry.span("aggregate"):
+        model_idxs, deltas, ws, alphas = [], [], [], []
+        any_stale = False
+        for model_idx, delta, weights, stal in buckets:
+            p = len(weights)
+            model_idxs.append(int(model_idx))
+            deltas.append(delta)
+            ws.append(jnp.asarray([float(x) for x in weights], jnp.float32))
+            if stal is None:
+                alphas.append(jnp.ones((p,), jnp.float32))
+            else:
+                scales = [staleness_scale(s, staleness_decay) for s in stal]
+                any_stale = any_stale or any(a != 1.0 for a in scales)
+                alphas.append(jnp.asarray(scales, jnp.float32))
+        if not deltas:
+            return (global_params, None) if with_stats else global_params
+        out, valid = _stacked_agg_program(
+            global_params, tuple(deltas), tuple(ws), tuple(alphas),
+            family=fam, model_idxs=tuple(model_idxs),
+            server_lr=float(server_lr), any_stale=any_stale,
+            use_kernel=use_kernel, interpret=interpret,
+            validate=bool(validate), mag_cap=float(mag_cap))
     return (out, valid) if with_stats else out
 
 

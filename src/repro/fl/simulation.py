@@ -25,7 +25,6 @@ Method arms:
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Dict
 
 import jax.numpy as jnp
@@ -302,7 +301,7 @@ def _run_once_reference(cfg: FLConfig, verbose=False, selector=None,
 
     hist = {"acc": [], "acc_mean": [], "energy": [], "round_time": [],
             "alive": [], "participants": [], "model_choices": [],
-            "reward": [], "wall_clock": [], "dropouts": 0}
+            "reward": [], "dropouts": 0}
     prev_acc = float(np.mean(fl_server.evaluate(global_params, x_val, y_val,
                                                 family=w.family)))
     e_prev = fleet_total_remaining(fleet)
@@ -310,7 +309,6 @@ def _run_once_reference(cfg: FLConfig, verbose=False, selector=None,
     rows = np.arange(n_total)
 
     for t in range(cfg.n_rounds):
-        t0 = time.time()
         if (cfg.hotplug_n and not hist_hotplug_done
                 and t >= cfg.hotplug_round):
             fleet = fleet_connect(fleet, cfg.n_devices, cfg.energy_scale)
@@ -400,7 +398,6 @@ def _run_once_reference(cfg: FLConfig, verbose=False, selector=None,
         hist["participants"].append(list(sel.participants))
         hist["model_choices"].append([sel.model_choice[i] for i in sel.participants])
         hist["reward"].append(reward)
-        hist["wall_clock"].append(time.time() - t0)
         if verbose:
             print(f"  round {t:3d}: acc={acc:.3f} exits="
                   f"{np.round(np.asarray(accs), 3)} alive={alive_now}"

@@ -29,6 +29,7 @@ from repro.fl import batch as fl_batch
 from repro.fl import client as fl_client
 from repro.fl import server as fl_server
 from repro.models import cnn
+from repro.models.family import get_family
 
 
 def _data(n=300, hw=8, seed=0):
@@ -327,3 +328,26 @@ def test_repeat_cohort_reuses_compiled_programs():
         fl_batch.run_cohort("drfl", params, x, y, parts, ids, ms, seeds,
                             **kw)
     assert fl_batch.COUNTERS["executions"] == 2 * first
+
+
+def test_counters_count_step_slots_and_real_steps():
+    """``slots`` counts every P_pad x T_pad step a bucket program ran and
+    ``real_steps`` the unmasked ones: the padding share is their ratio."""
+    x, y = _data()
+    fam = get_family("mlp")
+    params = fam.init(jax.random.PRNGKey(0), 10, width_mult=0.06, hw=8)
+    parts = [np.arange(0, 40), np.arange(40, 120), np.arange(120, 136)]
+    ids, ms = [0, 1, 2], [1, 1, 3]
+    seeds = [fl_client.client_update_seed(0, 0, i) for i in ids]
+    kw = dict(epochs=1, batch=32)
+    buckets = fl_batch.bucket_cohort(ids, ms, parts, seeds,
+                                     [float(len(p)) for p in parts], **kw)
+    fl_batch.reset_counters()
+    fl_batch.run_cohort("drfl", params, x, y, parts, ids, ms, seeds,
+                        lr=0.05, family=fam, **kw)
+    # model 1: clients of 1 and 2 steps in a [2, 2] schedule; model 3: one
+    # client of 16 images, one wrap-around batch
+    assert fl_batch.COUNTERS["slots"] == sum(
+        b.valid.size for b in buckets) == 4 + 1
+    assert fl_batch.COUNTERS["real_steps"] == sum(
+        int(b.valid.sum()) for b in buckets) == 3 + 1

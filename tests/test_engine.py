@@ -76,7 +76,7 @@ def test_sync_reports_straggler_wait():
     # heterogeneous tiers: some participant always outpaces the straggler
     assert h["engine"] == "sync"
     assert h["idle_time"] > 0.0
-    assert len(h["idle"]) == len(h["round_time"])
+    assert len(h["straggler_wait_s"]) == len(h["round_time"])
     assert h["sim_time_total"] == pytest.approx(sum(h["round_time"]))
 
 

@@ -15,6 +15,7 @@ import numpy as np
 import pytest
 
 from repro.checkpoint import CheckpointHalt
+from repro.checkpoint.engine import EngineCheckpointer
 from repro.core.selection import GreedySelector
 from repro.fl import (FaultEvent, FaultPlan, FLConfig, RoundEngine,
                       run_simulation)
@@ -46,8 +47,8 @@ def _canon(x):
 def _assert_bit_identical(ref, res):
     assert set(ref) == set(res)
     for k in ref:
-        if k in ("wall_clock", "params"):
-            continue                     # wall time is the one allowed diff
+        if k == "params":
+            continue
         assert _canon(ref[k]) == _canon(res[k]), f"hist[{k!r}] diverged"
     ra = jax.tree.leaves(ref["params"])
     rb = jax.tree.leaves(res["params"])
@@ -99,6 +100,29 @@ def test_async_set_mixer_batched_kill_resume_parity(tmp_path):
     cfg = FLConfig(**SMALL, engine_mode="async", client_executor="batched",
                    mixer_mode="set", marl_agent_budget=4, marl_episodes=2)
     _kill_and_resume(cfg, tmp_path, halt_after=3)
+
+
+@pytest.mark.parametrize("mode", ["sync", "async"])
+def test_resume_from_checkpoint_with_legacy_history_keys(tmp_path,
+                                                         monkeypatch, mode):
+    # checkpoints written before the straggler wait was renamed from
+    # ``idle`` and ``wall_clock`` was dropped resume under today's keys
+    load = EngineCheckpointer.load
+
+    def legacy_load(self, path=None):
+        state, meta = load(self, path)
+        hist = state["hist"]
+        hist["idle"] = hist.pop("straggler_wait_s")
+        hist["wall_clock"] = [0.5] * len(hist["idle"])
+        if mode == "async":
+            state["state"]["window_wall0"] = 0.5
+        return state, meta
+
+    monkeypatch.setattr(EngineCheckpointer, "load", legacy_load)
+    cfg = FLConfig(**SMALL, engine_mode=mode, selector="greedy",
+                   client_executor="perclient")
+    ref = _kill_and_resume(cfg, tmp_path)
+    assert "idle" not in ref and "wall_clock" not in ref
 
 
 def test_resume_rejects_config_drift(tmp_path):
