@@ -121,7 +121,9 @@ def round_batched(params, x_dev, y_dev, parts, ids, ms, seeds, family="cnn"):
     aggregation."""
     res = fl_batch.run_cohort(
         "drfl", params, x_dev, y_dev, [parts[i] for i in ids], ids, ms,
-        seeds, epochs=EPOCHS, batch=BATCH, lr=LR, family=family)
+        seeds, epochs=EPOCHS, batch=BATCH, lr=LR, family=family,
+        fleet_steps=fl_batch.longest_schedule(parts, epochs=EPOCHS,
+                                              batch=BATCH))
     new = fl_server.aggregate_drfl_stacked(
         params, [(b.model_idx, b.stacked_delta, b.weights, None)
                  for b in res.buckets], server_lr=SERVER_LR, family=family)
@@ -132,7 +134,7 @@ def round_batched(params, x_dev, y_dev, parts, ids, ms, seeds, family="cnn"):
 def bench_one(n: int, rounds: int, family: str = "cnn", seed: int = 0
               ) -> dict:
     x, y, parts, params = _setup(n, family, seed)
-    x_dev, y_dev = jnp.asarray(x), jnp.asarray(y)
+    x_dev, y_dev = fl_batch.resident(x, family), jnp.asarray(y)
 
     # warmup round 0 (compiles both paths) then time rounds 1..R
     ids, ms, seeds = _cohort(n, parts, 0, family, seed)

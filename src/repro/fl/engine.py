@@ -246,14 +246,23 @@ def resolve_client_executor(cfg) -> str:
 
 
 def _run_batched_cohort(cfg, world, global_params, device_ids, model_idxs,
-                        seeds, x_dev, y_dev) -> fl_batch.CohortResult:
+                        seeds, x_dev, y_dev,
+                        fleet_steps) -> fl_batch.CohortResult:
     """One bucketed-vmap executor pass for ``device_ids`` (all must have
     local data).  Weights default to shard sizes inside run_cohort."""
     return fl_batch.run_cohort(
         cfg.method, global_params, x_dev, y_dev,
         [world.parts[i] for i in device_ids], device_ids, model_idxs, seeds,
         epochs=cfg.local_epochs, batch=cfg.batch_size, lr=cfg.lr,
-        family=world.family)
+        family=world.family, fleet_steps=fleet_steps)
+
+
+def _resident_data(cfg, w):
+    """The batched executor's device-resident training set and the
+    fleet's longest local schedule, once per run."""
+    return (fl_batch.resident(w.x_tr, w.family), jnp.asarray(w.y_tr),
+            fl_batch.longest_schedule(w.parts, epochs=cfg.local_epochs,
+                                      batch=cfg.batch_size))
 
 
 def sync_task_budget(cfg) -> int:
@@ -508,11 +517,11 @@ class RoundEngine:
         selector, buffer = self.selector, self.buffer
         marl = selector if isinstance(selector, MarlSelector) else None
 
-        x_dev = y_dev = None
+        x_dev = y_dev = fleet_steps = None
         if self.executor == "batched":
             # training set stays device-resident: the bucketed executor
             # gathers mini-batches on device instead of per-step host copies
-            x_dev, y_dev = jnp.asarray(w.x_tr), jnp.asarray(w.y_tr)
+            x_dev, y_dev, fleet_steps = _resident_data(cfg, w)
 
         # energy scenario (repro.energy): every hook below is gated on the
         # python-level trivial_* flags, so the default config runs the exact
@@ -691,7 +700,7 @@ class RoundEngine:
                         cfg, w, global_params, cohort,
                         [int(choice[i]) for i in cohort],
                         [fl_client.client_update_seed(cfg.seed, t, i)
-                         for i in cohort], x_dev, y_dev)
+                         for i in cohort], x_dev, y_dev, fleet_steps)
                     if cfg.method == "drfl":
                         global_params, valid = (
                             fl_server.aggregate_drfl_stacked(
@@ -700,11 +709,11 @@ class RoundEngine:
                                   None) for b in res.buckets],
                                 server_lr=cfg.server_lr, family=w.family,
                                 with_stats=True))
-                        devs, models = [], []
-                        for b in res.buckets:
-                            pad = len(b.weights) - len(b.participants)
-                            devs += list(b.participants) + [None] * pad
-                            models += [b.model_idx] * len(b.weights)
+                        # one validity entry per client row, bucket order
+                        devs = [d for b in res.buckets
+                                for d in b.participants]
+                        models = [b.model_idx for b in res.buckets
+                                  for _ in b.participants]
                         if valid is not None:
                             self._qpend.append((
                                 {"devices": devs, "models": models,
@@ -718,6 +727,13 @@ class RoundEngine:
                             {"devices": [c[0] for c in contribs],
                              "models": [c[1] for c in contribs], "round": t,
                              "time": sim_time}, valid))
+                        del contribs
+                    # the round's client deltas die with its aggregation,
+                    # not when the next round's cohort replaces them: the
+                    # next bucket program's output would else sit beside
+                    # a second round of stacked deltas on the device (so
+                    # no loop variable above may hold a bucket either)
+                    del res
                     n_agg += 1
                 elif cohort:
                     deltas, idxs, weights = [], [], []
@@ -855,9 +871,9 @@ class RoundEngine:
             # jaxlint: allow(host-sync-in-hot-path) -- availability-scenario one-time setup pull of the host phase mirror
             tz_host = np.asarray(jax.device_get(fleet.tz_phase), np.float64)
 
-        x_dev = y_dev = None
+        x_dev = y_dev = fleet_steps = None
         if self.executor == "batched":
-            x_dev, y_dev = jnp.asarray(w.x_tr), jnp.asarray(w.y_tr)
+            x_dev, y_dev, fleet_steps = _resident_data(cfg, w)
 
         deadline_factor = float(getattr(cfg, "task_deadline_factor", 4.0))
         # per-task deadlines (and their reap events) exist only when faults
@@ -1134,7 +1150,7 @@ class RoundEngine:
                         cfg, w, global_params, with_data,
                         [int(choice[i]) for i in with_data],
                         [fl_client.client_update_seed(cfg.seed, cid, i)
-                         for i in with_data], x_dev, y_dev)
+                         for i in with_data], x_dev, y_dev, fleet_steps)
                     for b in res.buckets:
                         for r, dev in enumerate(b.participants):
                             rows_by_dev[dev] = (b, r)

@@ -129,56 +129,87 @@ def aggregate_drfl(global_params, deltas: List, model_idxs: List[int],
 # fused kernel dispatch (interpret mode on CPU, the MXU kernel on TPU)
 # instead of a tree.map over ~60 leaves per client.  The list-based path
 # above stays as the parity reference.
+#
+# Each bucket's client rows are first written, one client at a time, into
+# one [N, R, seg] stack of the round's clients, N pow2-padded
+# (``_stack_bucket_rows``, a program per bucket shape), so the averaging
+# program's shapes follow N alone and not which submodels the round's
+# buckets hold, and a bucket's pad rows are never copied to full-model
+# width.
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("family", "model_idx", "validate",
+                                    "mag_cap"),
+                   donate_argnums=(0, 1, 2))
+def _stack_bucket_rows(stack, mask, valid, global_params, delta, start, n,
+                       *, family, model_idx, validate, mag_cap):
+    """Write the first ``n`` client rows of a bucket's stacked submodel
+    delta (leaves ``[P, ...]``) into rows ``start .. start + n - 1`` of
+    ``stack`` [N, R, seg], ``mask`` [N, R] (the submodel's hold mask) and
+    ``valid`` [N], in place; ``start`` and ``n`` are data, so the program
+    is the bucket shape's alone.
+
+    Quarantine (``validate``): a client row that is non-finite anywhere or
+    exceeds ``mag_cap`` is written as zeros with a zero mask row — the
+    denominator's exact rescale then removes it from the mean, and its
+    zeros keep nan out of the numerator (0 * nan = nan) — and ``valid``
+    records it.  All-valid input is bit-for-bit the unvalidated rows."""
+    template = family.stack_template(global_params)
+    held = family.held_groups(global_params, model_idx)
+    row_mask = aggregation.group_row_mask(held, template)
+    groups = family.stack_groups(delta)
+
+    def one(i, carry):
+        stack, mask, valid = carry
+        row = jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False),
+            groups)
+        u = aggregation.stack_group_rows(row, template, held)   # [R, seg]
+        m = row_mask
+        ok = jnp.bool_(True)
+        if validate:
+            ok = aggregation.stacked_rows_valid(u[None], mag_cap)[0]
+            u = jnp.where(ok, u, 0.0)
+            m = m * ok.astype(m.dtype)
+        r = start + i
+        return (jax.lax.dynamic_update_index_in_dim(stack, u, r, 0),
+                jax.lax.dynamic_update_index_in_dim(mask, m, r, 0),
+                jax.lax.dynamic_update_index_in_dim(valid, ok, r, 0))
+
+    return jax.lax.fori_loop(0, n, one, (stack, mask, valid))
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("family", "model_idxs", "server_lr", "any_stale",
-                     "use_kernel", "interpret", "validate", "mag_cap"))
-def _stacked_agg_program(global_params, deltas, weights, alphas, *,
-                         family, model_idxs, server_lr, any_stale,
-                         use_kernel, interpret, validate=True,
-                         mag_cap=DELTA_MAG_CAP):
-    """The whole of DR-FL Step 2 as ONE jit program: flatten bucket-stacked
-    deltas into [N, R, seg] rows, quarantine poisoned rows, masked-mean
-    (Pallas kernel on TPU / fused einsum elsewhere), scatter the averaged
-    rows back onto the global tree.  Compiled once per (family, bucket
-    model indices, padded shapes).
+    static_argnames=("family", "server_lr", "any_stale", "use_kernel",
+                     "interpret"))
+def _stacked_agg_program(global_params, stack, mask, weights, alphas, *,
+                         family, server_lr, any_stale, use_kernel,
+                         interpret):
+    """DR-FL Step 2 over the round's stacked client rows as ONE jit
+    program: masked-mean (Pallas kernel on TPU / fused einsum elsewhere),
+    scatter the averaged rows back onto the global tree.  Compiled once
+    per (family, number of rows N: the round's clients, pow2-padded).
 
-    Quarantine (``validate``): a client row that is non-finite anywhere or
-    exceeds ``mag_cap`` gets its mask column zeroed — the denominator's
-    exact rescale then removes it from the mean — and its elements zeroed
-    (0 * nan = nan, so masking alone cannot keep nan out of the
-    numerator).  All-valid input is bit-for-bit the unvalidated program.
-    Returns ``(new_params, valid)`` with ``valid`` a [N_total] device bool
-    (None when validation is off)."""
+    stack: [N, R, seg] client rows (zeros outside held groups and in
+    quarantined rows, :func:`_stack_bucket_rows`); mask: [N, R] 0/1 hold
+    masks; weights, alphas: [N]."""
     template = family.stack_template(global_params)
-    us, row_masks = [], []
-    for model_idx, delta in zip(model_idxs, deltas):
-        held = family.held_groups(global_params, model_idx)
-        u = aggregation.stack_group_rows(family.stack_groups(delta),
-                                         template, held,
-                                         stacked=True)        # [P, R, seg]
-        row_mask = aggregation.group_row_mask(held, template)  # [R]
-        us.append(u)
-        row_masks.append(
-            jnp.broadcast_to(row_mask, (u.shape[0], template.n_rows)))
-    u_all = jnp.concatenate(us, axis=0)
-    m_all = jnp.concatenate(row_masks, axis=0)
-    w_all = jnp.concatenate(weights)
-    a_all = jnp.concatenate(alphas) if any_stale else None
-    valid = None
-    if validate:
-        valid = aggregation.stacked_rows_valid(u_all, mag_cap)  # [N_total]
-        u_all = jnp.where(valid[:, None, None], u_all, 0.0)
-        m_all = m_all * valid[:, None].astype(m_all.dtype)
     rows = aggregation.stacked_masked_mean(
-        u_all, m_all, w_all, a_all, interpret=interpret,
-        use_kernel=use_kernel)
+        stack, mask, weights, alphas if any_stale else None,
+        interpret=interpret, use_kernel=use_kernel)
     new_groups = aggregation.unstack_apply(family.stack_groups(global_params),
                                            rows, template,
                                            server_lr=server_lr)
-    return family.unstack_groups(global_params, new_groups), valid
+    return family.unstack_groups(global_params, new_groups)
+
+
+def _client_rows(weights) -> int:
+    """A bucket's client rows: through its last nonzero weight (the rows
+    after it are pow2 padding)."""
+    nz = np.flatnonzero(np.asarray(weights, np.float64))
+    return int(nz[-1]) + 1 if len(nz) else 0
 
 
 def aggregate_drfl_stacked(global_params, buckets, server_lr: float = 1.0,
@@ -192,43 +223,63 @@ def aggregate_drfl_stacked(global_params, buckets, server_lr: float = 1.0,
 
     ``buckets``: iterable of ``(model_idx, stacked_delta, weights,
     staleness)`` where ``stacked_delta`` is the submodel pytree with a
-    leading participant axis ``[P, ...]`` (repro.fl.batch.BucketResult —
-    pow2-padded rows carry weight 0.0 and drop out of the weighted mean
-    exactly), ``weights`` has P data sizes, and ``staleness`` is None or P
-    counts.  Staleness alphas are folded into the mask matrix numerator
-    with the denominator kept at the 0/1 hold mask (absolute FedAsync
-    damping, same semantics as :func:`aggregate_drfl`); all-fresh input
-    skips the rescale so it is exactly the plain masked mean.
+    leading participant axis ``[P, ...]`` (repro.fl.batch.BucketResult),
+    ``weights`` has P data sizes, and ``staleness`` is None or P counts.
+    A bucket's client rows run through its last nonzero weight; the rows
+    after it are pow2 padding (weight 0.0, out of the weighted mean
+    exactly) and are left out of the stack.  Staleness alphas are folded
+    into the mask matrix numerator with the denominator kept at the 0/1
+    hold mask (absolute FedAsync damping, same semantics as
+    :func:`aggregate_drfl`); all-fresh input skips the rescale so it is
+    exactly the plain masked mean.
 
-    ``validate``/``mag_cap``: see :func:`_stacked_agg_program` (quarantine
-    of poisoned rows; padded rows with garbage contents are harmless either
-    way — their weight is already 0 — but quarantine also zeroes them, so
-    a non-finite pad row can no longer poison the numerator).
-    ``with_stats`` returns ``(params, valid)`` with the [N_total] row
-    validity left ON DEVICE — callers batch the pull."""
+    ``validate``/``mag_cap``: see :func:`_stack_bucket_rows` (quarantine
+    of poisoned rows).  ``with_stats`` returns ``(params, valid)`` with
+    the row validity left ON DEVICE — callers batch the pull — one entry
+    per client row, in bucket order, then True for the stack's pad rows."""
     fam = resolve_family(family)
     with telemetry.span("aggregate"):
-        model_idxs, deltas, ws, alphas = [], [], [], []
+        parts, ws, alphas = [], [], []
         any_stale = False
         for model_idx, delta, weights, stal in buckets:
-            p = len(weights)
-            model_idxs.append(int(model_idx))
-            deltas.append(delta)
-            ws.append(jnp.asarray([float(x) for x in weights], jnp.float32))
+            n = _client_rows(weights)
+            if n == 0:
+                continue
+            parts.append((int(model_idx), delta, n))
+            ws += [float(x) for x in weights[:n]]
             if stal is None:
-                alphas.append(jnp.ones((p,), jnp.float32))
+                alphas += [1.0] * n
             else:
-                scales = [staleness_scale(s, staleness_decay) for s in stal]
+                scales = [staleness_scale(s, staleness_decay)
+                          for s in stal[:n]]
                 any_stale = any_stale or any(a != 1.0 for a in scales)
-                alphas.append(jnp.asarray(scales, jnp.float32))
-        if not deltas:
+                alphas += scales
+        if not parts:
             return (global_params, None) if with_stats else global_params
-        out, valid = _stacked_agg_program(
-            global_params, tuple(deltas), tuple(ws), tuple(alphas),
-            family=fam, model_idxs=tuple(model_idxs),
+        # the stack's rows are pow2-padded too (zeros of weight 0.0), so the
+        # kernel reads the rows it read when each bucket came stacked whole
+        n_all = 1 << (len(ws) - 1).bit_length()
+        ws += [0.0] * (n_all - len(ws))
+        alphas += [1.0] * (n_all - len(alphas))
+        template = fam.stack_template(global_params)
+        stack = jnp.zeros((n_all, template.n_rows, template.seg),
+                          jnp.float32)
+        mask = jnp.zeros((n_all, template.n_rows), jnp.float32)
+        valid = jnp.ones((n_all,), bool)
+        start = 0
+        for model_idx, delta, n in parts:
+            stack, mask, valid = _stack_bucket_rows(
+                stack, mask, valid, global_params, delta, start, n,
+                family=fam, model_idx=model_idx, validate=bool(validate),
+                mag_cap=float(mag_cap))
+            start += n
+        out = _stacked_agg_program(
+            global_params, stack, mask, jnp.asarray(ws, jnp.float32),
+            jnp.asarray(alphas, jnp.float32), family=fam,
             server_lr=float(server_lr), any_stale=any_stale,
-            use_kernel=use_kernel, interpret=interpret,
-            validate=bool(validate), mag_cap=float(mag_cap))
+            use_kernel=use_kernel, interpret=interpret)
+    if not validate:
+        valid = None
     return (out, valid) if with_stats else out
 
 

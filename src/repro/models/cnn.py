@@ -270,8 +270,20 @@ class CnnFamily(LayerwiseFamily):
         # batched convs as patches+einsum (batched GEMMs) instead
         if jax.default_backend() == "cpu":
             return conv_via_patches()
-        import contextlib
         return contextlib.nullcontext()
+
+    def bucket_clients_in_turn(self) -> bool:
+        # off CPU, vmapped per-client convs lower to grouped convolutions
+        # at about twice a lone step's time, and a 32-image step fills the
+        # chip by itself; XLA CPU runs convs inside a loop several times
+        # slower, so CPU keeps the unrolled vmapped scan
+        return jax.default_backend() != "cpu"
+
+    def samples_from_rows(self, params, rows):
+        # square NHWC images, C being the stem's input channels
+        c = params["stem"]["conv"].shape[2]
+        hw = math.isqrt(rows.shape[1] // c)
+        return rows.reshape(rows.shape[0], hw, hw, c)
 
 
 register_family(CnnFamily())

@@ -37,7 +37,8 @@ Public surface (one-line contracts):
   (depth-prefix views + size accounting), ``update_mask`` /
   ``stack_groups`` / ``stack_template`` / ``held_groups`` /
   ``unstack_groups`` (aggregation layout), ``loss_fn`` /
-  ``client_update`` / ``bucket_trace_context`` (client training),
+  ``client_update`` / ``bucket_trace_context`` /
+  ``bucket_clients_in_turn`` / ``samples_from_rows`` (client training),
   ``cost_model`` (paper-scale Eq. 5/7 calibration),
   ``state_summary_width`` / ``fleet_summary`` (the factored QMIX global
   state, sized by the family not the fleet), ``supports`` /
@@ -183,6 +184,20 @@ class ModelFamily:
         this family's forward pass (families may swap in vmap-friendly
         formulations, e.g. the CNN's patches-conv on CPU)."""
         return contextlib.nullcontext()
+
+    def bucket_clients_in_turn(self) -> bool:
+        """Whether a bucket program runs its clients one after another,
+        each for its own steps only, instead of vmapping a masked scan
+        over padded lanes.  Worth it where one client's step already fills
+        the device, so lanes buy nothing and pad steps cost whole passes."""
+        return False
+
+    def samples_from_rows(self, params, rows):
+        """A batch gathered from the training set held as rows ``[B, D]``
+        (``repro.fl.batch.resident``, where clients run in turn) as the
+        samples ``params``' model takes.  Samples that are rows already
+        (feature vectors, token sequences) pass unchanged."""
+        return rows
 
     # -- cost model -------------------------------------------------------
     def cost_model(self, num_classes: int = 10

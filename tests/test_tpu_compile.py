@@ -89,3 +89,75 @@ def test_flash_attention_compiles(one_chip, q_shape, kv_shape, causal,
     c = _compile(fn, one_chip, (q_shape, jnp.float32),
                  (kv_shape, jnp.float32), (kv_shape, jnp.float32))
     _assert_kernel(c, "flash_attention")
+
+
+def test_bucket_program_in_turn_holds_no_copy_of_the_training_set(
+        one_chip, monkeypatch):
+    """The CNN's bucket program, clients in turn, on the training set as
+    ``fl.batch.resident`` holds it (rows): its temporaries hold one
+    client's training and no copy of the set, which an image-shaped set
+    (or a conversion hoisted out of the step loop) would add."""
+    from repro.fl import batch as fl_batch
+    from repro.models.family import get_family
+    fam = get_family("cnn")
+    monkeypatch.setattr(fam, "bucket_clients_in_turn", lambda: True)
+    params = jax.eval_shape(lambda: fam.init(
+        jax.random.PRNGKey(0), 10, width_mult=0.25, hw=32))
+    sub = jax.eval_shape(lambda p: fam.submodel_params("drfl", p, 3), params)
+    n, p_pad, t_pad, batch = 32768, 4, 8, 32
+
+    def shape(s, d):
+        return jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+
+    args = (jax.tree.map(lambda a: shape(a.shape, a.dtype), sub),
+            shape((n, 32 * 32 * 3), jnp.float32), shape((n,), jnp.int32),
+            shape((p_pad, t_pad, batch), jnp.int32),
+            shape((p_pad, t_pad), jnp.float32))
+    fl_batch._bucket_program.clear_cache()
+    try:
+        mem = fl_batch._bucket_program.lower(
+            *args, method="drfl", lr=0.05, family=fam).compile(
+            ).memory_analysis()
+    finally:
+        fl_batch._bucket_program.clear_cache()
+    set_bytes = n * 32 * 32 * 3 * 4
+    tree_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree.leaves(sub))
+    assert mem.output_size_in_bytes >= p_pad * tree_bytes
+    # a bfloat16 copy of the set alone is half its bytes
+    assert mem.temp_size_in_bytes < set_bytes // 4, mem
+
+
+def test_stacked_aggregation_averages_the_stack_in_place(one_chip):
+    """The CNN's averaging program, with its ``layer_agg`` kernel, over a
+    round's stacked client rows: it makes no copy of the stack, and
+    stacking a bucket's rows into it updates the stack in place."""
+    from repro.fl import server as fl_server
+    from repro.models.family import get_family
+    fam = get_family("cnn")
+    params = jax.eval_shape(lambda: fam.init(
+        jax.random.PRNGKey(0), 10, width_mult=0.25, hw=32))
+    t = fam.stack_template(params)
+    n, p = 16, 8
+
+    def shape(s, d=jnp.float32):
+        return jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+
+    gp = jax.tree.map(lambda a: shape(a.shape, a.dtype), params)
+    stack, mask = shape((n, t.n_rows, t.seg)), shape((n, t.n_rows))
+    stack_bytes = n * t.n_rows * t.seg * 4
+    agg = fl_server._stacked_agg_program.lower(
+        gp, stack, mask, shape((n,)), shape((n,)), family=fam,
+        server_lr=1.0, any_stale=False, use_kernel=True,
+        interpret=False).compile()
+    _assert_kernel(agg, "layer_agg")
+    assert agg.memory_analysis().temp_size_in_bytes < stack_bytes // 4
+    delta = jax.tree.map(
+        lambda a: shape((p,) + a.shape, a.dtype),
+        jax.eval_shape(lambda q: fam.submodel_params("drfl", q, 3), params))
+    rows = fl_server._stack_bucket_rows.lower(
+        stack, mask, shape((n,), jnp.bool_), gp, delta, shape((), jnp.int32),
+        shape((), jnp.int32), family=fam, model_idx=3, validate=True,
+        mag_cap=fl_server.DELTA_MAG_CAP).compile().memory_analysis()
+    assert rows.alias_size_in_bytes >= stack_bytes
+    assert rows.temp_size_in_bytes < stack_bytes // 4
